@@ -40,9 +40,9 @@ D = 8                                 # item payload: D int32s ~ a record
 
 
 def main():
-    from repro.launch.mesh import make_mesh
+    from repro.launch.mesh import make_data_mesh
 
-    mesh = make_mesh((SHARDS,), (dist.AXIS,))
+    mesh = make_data_mesh(SHARDS)
     step = functools.partial(dist.drtbs_shard_step, n=N_GLOBAL, lam=LAM)
 
     def shard_fn(key, items, nfull, partial, weight, tweight, oflow, bi, bc):
@@ -83,13 +83,14 @@ def main():
                 st.total_weight, st.overflow[None])
 
     smapped = jax.jit(
-        dist.shard_map(
+        jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(), P(dist.AXIS), P(dist.AXIS), P(), P(), P(),
                       P(dist.AXIS), P(dist.AXIS), P(dist.AXIS)),
             out_specs=(P(dist.AXIS), P(dist.AXIS), P(), P(), P(),
                        P(dist.AXIS)),
+            check_vma=False,
         )
     )
 

@@ -2,12 +2,33 @@
 from __future__ import annotations
 
 import json
+import os
 import pathlib
 import time
 
 import numpy as np
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
+
+def host_mesh_env() -> dict:
+    """Environment for a child process that times a mesh of forced host
+    (CPU) devices. A chip belongs to one process, and the parent of such a
+    child has already touched JAX, so these suites run only where JAX is
+    pinned to the CPU (``JAX_PLATFORMS=cpu``); anywhere else they refuse
+    rather than start a child that would need the parent's device."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise SystemExit(
+            "host-device mesh suites run with JAX_PLATFORMS=cpu only: their "
+            "child processes would need the device the parent holds"
+        )
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (
+        str(REPO_ROOT / "src") + os.pathsep + str(REPO_ROOT)
+        + os.pathsep + env.get("PYTHONPATH", "")
+    )
+    env.pop("XLA_FLAGS", None)  # the worker sets its own device count
+    return env
+
 
 #: every BENCH_*.json written by a suite follows this shape (validated by
 #: benchmarks/check_bench.py and the CI bench-smoke job):

@@ -15,25 +15,19 @@ rather than the bare sampler step -- the Sec. 5 algorithms driving the
 Sec. 6 experiment harness in one program (protocol in EXPERIMENTS.md)."""
 from __future__ import annotations
 
-import os
-import pathlib
 import subprocess
 import sys
 
-HERE = pathlib.Path(__file__).parent
-SRC = str(HERE.parent / "src")
+from .common import REPO_ROOT, host_mesh_env
 
 
 def _worker(shards, bps, impl, timeout=600):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + str(HERE.parent) + os.pathsep + \
-        env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
+    env = host_mesh_env()
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks._dist_worker",
          str(shards), str(bps), impl],
         capture_output=True, text=True, timeout=timeout, env=env,
-        cwd=str(HERE.parent),
+        cwd=str(REPO_ROOT),
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = proc.stdout.strip().splitlines()[-1]

@@ -34,8 +34,6 @@ documents the host-mesh caveat.
 """
 from __future__ import annotations
 
-import os
-import pathlib
 import subprocess
 import sys
 
@@ -53,7 +51,7 @@ from repro.manage import (
 )
 from repro.manage.loop import item_proto
 
-from .common import smoke_mode, time_fn, write_bench_json
+from .common import REPO_ROOT, host_mesh_env, smoke_mode, time_fn, write_bench_json
 
 T = 200
 B = 100
@@ -64,24 +62,18 @@ SB = 8                      # superbatch chunk size for the fused_sb rows
 STEP_CAP = 4096             # sampler-step criterion capacity
 STEP_BCAP = 512
 
-HERE = pathlib.Path(__file__).parent
 
 
 def _sharded_worker(shards: int, mode: str, timeout=600) -> float:
     """us/tick of the sharded loop in a subprocess with ``shards`` forced
     host devices (the device count is locked at jax init, so each point
     needs its own process -- same pattern as fig789)."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (
-        str(HERE.parent / "src") + os.pathsep + str(HERE.parent)
-        + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    env.pop("XLA_FLAGS", None)  # the worker sets its own device count
+    env = host_mesh_env()
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks._sharded_loop_worker",
          str(shards), mode],
         capture_output=True, text=True, timeout=timeout, env=env,
-        cwd=str(HERE.parent),
+        cwd=str(REPO_ROOT),
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     return float(proc.stdout.strip().splitlines()[-1].split(",")[1])

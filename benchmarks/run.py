@@ -20,6 +20,9 @@ report). Prints ``name,us_per_call,derived`` CSV.
 
 Select with ``python -m benchmarks.run [names...]`` (default: all).
 ``--smoke`` / BENCH_SMOKE=1 shrinks the json-emitting suites to CI size.
+A suite that fails prints an ERROR row and the run exits non-zero. All
+suites run in this one process; ``fig789`` and ``manage`` time host-device
+meshes in child processes and so run only under ``JAX_PLATFORMS=cpu``.
 """
 from __future__ import annotations
 
@@ -33,7 +36,11 @@ SUITES = ["fig1", "table1", "fig12", "fig13", "fig789", "manage", "sampler",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = [a for a in sys.argv[1:] if a != "--smoke"] or SUITES
+    failed = []
     for name in args:
         t0 = time.time()
         if name == "fig1":
@@ -64,9 +71,12 @@ def main() -> None:
             rows = m.run()
         except Exception as e:  # noqa: BLE001
             print(f"{name},0.0,ERROR={e!r}", flush=True)
+            failed.append(name)
             continue
         emit(rows)
         print(f"# {name} done in {time.time()-t0:.1f}s", flush=True)
+    if failed:
+        raise SystemExit(f"suites failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -37,20 +37,6 @@ from . import rng
 AXIS = "data"  # mesh axis the reservoir is co-partitioned over
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: new-style (``check_vma``) when
-    available, else ``jax.experimental.shard_map`` (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
 # ---------------------------------------------------------------------------
 # state
 # ---------------------------------------------------------------------------
@@ -459,10 +445,11 @@ def make_drtbs_step(mesh, item_spec, *, n: int, lam: float, axis: str = AXIS):
         overflow=P(axis),
     )
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             sharded,
             mesh=mesh,
             in_specs=(P(), state_specs, item_spec, P(axis)),
             out_specs=state_specs,
+            check_vma=False,
         )
     )

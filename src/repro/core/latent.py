@@ -98,21 +98,16 @@ def realize(key: jax.Array, lat: Latent) -> tuple[jax.Array, jax.Array]:
 
 
 def compact_items(items: Any, mask: jax.Array) -> Any:
-    """Tree-wide stable pack of the masked rows to the buffer head via the
-    :mod:`repro.kernels.reservoir_compact` kernel (Pallas on TPU, jnp oracle
-    elsewhere). Leaves may have any trailing shape (flattened to [cap, D]);
-    rows past ``mask.sum()`` come back zero. THE pack primitive behind every
-    materialization path (:func:`realize_compact` here,
+    """Tree-wide stable pack of the masked rows to the buffer head via
+    :func:`repro.kernels.reservoir_compact.ops.reservoir_compact` (Pallas
+    row gather on TPU, jnp oracle elsewhere). Leaves may have any trailing
+    shape; rows past ``mask.sum()`` come back zero. THE pack primitive behind
+    every materialization path (:func:`realize_compact` here,
     :func:`repro.core.api.materialize_view` and through it the distributed
     ``extract_global`` closures)."""
     from repro.kernels.reservoir_compact import ops as rc_ops
 
-    def pack(leaf):
-        flat = leaf.reshape(leaf.shape[0], -1)
-        out, _ = rc_ops.reservoir_compact(flat, mask)
-        return out.reshape(leaf.shape)
-
-    return jax.tree_util.tree_map(pack, items)
+    return rc_ops.reservoir_compact(items, mask)[0]
 
 
 def realize_compact(key: jax.Array, lat: Latent) -> tuple[Any, jax.Array]:

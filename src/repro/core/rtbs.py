@@ -96,7 +96,11 @@ def tick_map(key, nfull, weight, total_weight, bcount, decay, *,
     C = weight
     k0 = nfull
     was_unsat = w_prev < n
-    w_dec = decay * w_prev
+    # the select keeps the product rounded on its own: a bare d*W + B may
+    # or may not be contracted into one fused multiply-add depending on how
+    # the surrounding program is fused, so the bank and the single-reservoir
+    # step would round W differently (W >= 0, so the select changes nothing)
+    w_dec = jnp.where(w_prev > 0, decay * w_prev, 0.0)
     w_new = w_dec + bf                # both Alg. 2 branches decay then add B
     still_sat = (~was_unsat) & (w_new >= n)
 

@@ -6,18 +6,16 @@
   * ssd_scan         -- Mamba2 SSD chunked scan: per-chunk quadratic intra
                         work + the inter-chunk state recurrence carried in a
                         VMEM scratch accumulator.
-  * reservoir_compact -- fused keep-mask prefix-sum + one-hot-matmul
-                        compaction of reservoir buffers (the TPU-native
-                        replacement for Spark's in-place RDD update trick;
-                        DESIGN.md Sec. 3) -- wired into sample
-                        materialization (``latent.realize_compact`` /
-                        ``api.materialize_view``).
   * tbs_step         -- the sampler hot path: a whole R-TBS tick's composed
-                        slot map applied as ONE VMEM-resident two-source
-                        payload pass (reservoir + arriving batch, one-hot
-                        MXU scatter; DESIGN.md Sec. 11).
+                        slot map applied as ONE two-source row gather
+                        (reservoir + arriving batch; 32-bit word copies, so
+                        bit-exact for every dtype; DESIGN.md Sec. 11).
+  * reservoir_compact -- stable keep-mask compaction for sample
+                        materialization (``latent.compact_items`` /
+                        ``api.materialize_view``): a jnp prefix sum builds
+                        the slot map, the ``tbs_step`` gather applies it.
 
-Each kernel ships ``ops.py`` (backend-keyed jit wrapper: compiled Pallas on
+Each package ships ``ops.py`` (backend-keyed jit wrapper: compiled Pallas on
 TPU, jnp oracle off-TPU, ``impl="interpret"`` for CPU CI kernel validation)
 and ``ref.py`` (pure-jnp oracle); tests sweep shapes/dtypes with
 assert_allclose.

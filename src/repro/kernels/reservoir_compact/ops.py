@@ -1,16 +1,14 @@
-"""jit wrapper for reservoir compaction.
+"""Reservoir compaction: pack the masked rows to the buffer head.
 
-Implementation routing (``impl``): ``None`` auto-selects the compiled Pallas
-kernel on TPU and the pure-jnp oracle elsewhere (the oracle is the fast CPU
-path; ``"interpret"`` runs the kernel body under the Pallas interpreter for
-CPU CI validation, ``"pallas"`` forces compilation).
+Each survivor's destination slot is an exclusive prefix sum of the mask,
+computed in jnp; its inverse is a one-source slot map that the ``tbs_step``
+row-gather kernel applies (slot ``cap`` reads a zero row, so rows past the
+count come back zero). There is no compaction kernel of its own: the
+prefix sum is O(cap) integer work, and the payload moves through the same
+bit-exact gather as the sampler step.
 
-The backend choice is resolved OUTSIDE the jit boundary and passed as a
-static argument so it participates in the jit cache key. The previous
-wrapper called ``jax.default_backend()`` at trace time inside a jit keyed
-only on ``block``: the first call froze the interpret/compiled decision for
-the process lifetime, silently running interpret-mode kernels after a
-backend flip (or vice versa).
+``impl`` routes as in :mod:`repro.kernels.tbs_step.ops` (``None`` = the
+compiled kernel on TPU, the jnp oracle elsewhere).
 """
 from __future__ import annotations
 
@@ -19,38 +17,33 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import kernel, ref
+from repro.kernels.tbs_step import ops as tbs_ops
 
-
-def _auto_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+from . import ref
 
 
 @functools.partial(jax.jit, static_argnames=("block", "impl"))
 def _compact(items, mask, *, block, impl):
+    cap = mask.shape[0]
+    m = mask.astype(jnp.int32)
     if impl == "ref":
-        return ref.compact_ref(items, mask)
-    cap, D = items.shape
-    b = min(block, cap)
-    pad = -cap % b
-    if pad:  # kernel requires cap % block == 0; padded rows are masked out
-        items = jnp.concatenate([items, jnp.zeros((pad, D), items.dtype)])
-        mask = jnp.concatenate([mask, jnp.zeros((pad,), mask.dtype)])
-    out, cnt = kernel.compact(
-        items, mask, block=b, interpret=(impl == "interpret")
-    )
-    return out[:cap], cnt
+        def one(leaf):
+            out, _ = ref.compact_ref(leaf.reshape(cap, -1), mask)
+            return out.reshape(leaf.shape)
+
+        return jax.tree_util.tree_map(one, items), jnp.sum(m)
+    dest = jnp.where(mask, jnp.cumsum(m) - m, cap)
+    src = jnp.full((cap,), cap, jnp.int32).at[dest].set(
+        jnp.arange(cap, dtype=jnp.int32), mode="drop")
+    zero = jax.tree_util.tree_map(
+        lambda a: jnp.zeros((1,) + a.shape[1:], a.dtype), items)
+    out = tbs_ops.tbs_step_apply(items, zero, src, block=block, impl=impl)
+    return out, jnp.sum(m)
 
 
 def reservoir_compact(items, mask, *, block=128, impl=None):
-    """items [cap, D]; mask [cap] bool -> (compacted [cap, D], count).
-    Stable: surviving rows keep their relative order. ``impl`` as per the
-    module docstring; any ``cap`` is accepted (padded to the block size), and
-    bool / sub-int32 integer payloads are widened for the one-hot matmul and
-    cast back."""
-    if impl is None:
-        impl = _auto_impl()
-    dt = items.dtype
-    wide = dt if jnp.issubdtype(dt, jnp.floating) or dt == jnp.int32 else jnp.int32
-    out, cnt = _compact(items.astype(wide), mask, block=block, impl=impl)
-    return out.astype(dt), cnt
+    """items: pytree of [cap, ...] leaves; mask [cap] bool -> (compacted
+    items, count int32). Stable: surviving rows keep their relative order;
+    rows past the count are zero. Any dtype, bit-exact."""
+    return _compact(items, mask, block=block,
+                    impl=tbs_ops.resolve_impl(impl))

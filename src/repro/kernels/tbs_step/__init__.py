@@ -4,10 +4,8 @@ A full R-TBS tick (paper Alg. 2: decay -> downsample -> batch insert /
 victim replacement) is expressed by :mod:`repro.core.rtbs` as a single
 slot-index map ``src[cap]`` over TWO sources -- the old reservoir
 (``src < cap``) and the arriving batch (``src >= cap``) -- composed from the
-per-stage maps in O(cap) integer ops. This kernel applies that map in one
-VMEM-resident pass: payload rows move HBM -> VMEM -> HBM exactly once per
-tick, with the row selection done as one-hot matmuls on the MXU (the
-TPU-native substitute for vector gather, same idiom as
-:mod:`repro.kernels.reservoir_compact`). See DESIGN.md Sec. 11.
+per-stage maps in O(cap) integer ops. The kernel applies that map as one
+row gather by DMA of 32-bit words: each payload row moves HBM -> HBM once
+per tick, and every dtype is copied bit for bit. See DESIGN.md Sec. 11.
 """
 from . import ops, ref  # noqa: F401

@@ -2,12 +2,19 @@
 
 The sampler step (decay-downsample slot map + batch insert + victim
 replacement, composed by :mod:`repro.core.rtbs` into one ``src`` map) is a
-two-source gather: output slot i pulls from reservoir row ``src[i]`` when
-``src[i] < cap``, else from batch row ``src[i] - cap``. Both sources stay
-resident in VMEM across the sequential grid; each output block builds two
-one-hot selection matrices ([block, cap] / [block, bcap], never leaving VMEM)
-and scatters the rows via MXU matmuls. Payload rows therefore move
-HBM -> VMEM -> HBM exactly once per tick."""
+two-source row gather: output row i of key t pulls reservoir row
+``src[t, i]`` when ``src[t, i] < cap``, else batch row ``src[t, i] - cap``.
+
+Each output row is one DMA from its source row in HBM straight to its slot
+in the output in HBM. The payload never passes through VMEM, so VMEM use is
+nil whatever the row width D or the capacity, and no arithmetic touches it:
+:mod:`.ops` hands the kernel 32-bit words, so every dtype comes back bit for
+bit. The grid is (key, row block); a row block's slice of ``src`` sits in
+SMEM, and at most ``INFLIGHT`` row copies are outstanding at a time.
+
+Rows are laid out ``[L, 128]`` (words padded to whole 128-lane rows): the
+copy then slices only the untiled row axis, which the DMA engine can address
+one row at a time."""
 from __future__ import annotations
 
 import functools
@@ -15,107 +22,78 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+INFLIGHT = 16
 
 
-def _kernel(src_ref, items_ref, batch_ref, out_ref, *, cap, bcap):
-    block = out_ref.shape[0]
-    src = src_ref[...][:, 0]                       # [block] int32
-    items = items_ref[...]                         # [cap, D]
-    batch = batch_ref[...]                         # [bcap, D]
-    jj = jax.lax.broadcasted_iota(jnp.int32, (block, cap), 1)
-    sel_i = ((jj == src[:, None]) & (src[:, None] < cap)).astype(items.dtype)
-    kk = jax.lax.broadcasted_iota(jnp.int32, (block, bcap), 1)
-    sel_b = ((kk == (src[:, None] - cap)) & (src[:, None] >= cap)).astype(
-        batch.dtype
-    )
-    out_ref[...] = jax.lax.dot_general(
-        sel_i, items, (((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    ) + jax.lax.dot_general(
-        sel_b, batch, (((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
+def _kernel(src_ref, items_hbm, batch_hbm, out_hbm, sem, *, cap, bcap, block):
+    t = pl.program_id(0)
+    base = pl.program_id(1) * block
+    n = jnp.minimum(block, cap - base)
+
+    def wait_one():
+        # waits for one row's bytes: every copy of the step is one row
+        pltpu.make_async_copy(items_hbm.at[t, pl.ds(0, 1)],
+                              out_hbm.at[t, pl.ds(0, 1)], sem).wait()
+
+    def start(r, carry):
+        @pl.when(r >= INFLIGHT)
+        def _():
+            wait_one()
+
+        s = src_ref[0, r]
+        dst = out_hbm.at[t, pl.ds(base + r, 1)]
+
+        @pl.when(s < cap)
+        def _():
+            row = jnp.maximum(s, 0)
+            pltpu.make_async_copy(items_hbm.at[t, pl.ds(row, 1)], dst,
+                                  sem).start()
+
+        @pl.when(s >= cap)
+        def _():
+            row = jnp.minimum(s - cap, bcap - 1)
+            pltpu.make_async_copy(batch_hbm.at[t, pl.ds(row, 1)], dst,
+                                  sem).start()
+
+        return carry
+
+    def drain(r, carry):
+        wait_one()
+        return carry
+
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, jnp.minimum(n, INFLIGHT), drain, 0)
 
 
-def _kernel_banked(src_ref, items_ref, batch_ref, out_ref, *, cap, bcap):
-    # the banked body is the single-reservoir kernel with a leading
-    # size-1 bank block: each (key, block) grid step rewrites one output
-    # block of one touched key's reservoir from that key's two VMEM-resident
-    # sources
-    block = out_ref.shape[1]
-    src = src_ref[...][0, :, 0]                    # [block] int32
-    items = items_ref[...][0]                      # [cap, D]
-    batch = batch_ref[...][0]                      # [bcap, D]
-    jj = jax.lax.broadcasted_iota(jnp.int32, (block, cap), 1)
-    sel_i = ((jj == src[:, None]) & (src[:, None] < cap)).astype(items.dtype)
-    kk = jax.lax.broadcasted_iota(jnp.int32, (block, bcap), 1)
-    sel_b = ((kk == (src[:, None] - cap)) & (src[:, None] >= cap)).astype(
-        batch.dtype
-    )
-    out_ref[0, ...] = jax.lax.dot_general(
-        sel_i, items, (((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    ) + jax.lax.dot_general(
-        sel_b, batch, (((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
-
-
-def apply_banked(items, batch, src, *, block=128, interpret=False):
-    """The bank grid dimension (DESIGN.md Sec. 13): items [T, cap, D];
-    batch [T, bcap, D]; src [T, capP] int32 (capP >= cap a multiple of
-    ``block``, entries in [0, cap + bcap)) -> out [T, capP, D] with
-    out[t, i] = items[t, src[t, i]] if src[t, i] < cap else
-    batch[t, src[t, i] - cap]. One launch advances every touched key:
-    grid = (T, capP // block) with the leading axis selecting the key row.
-    Parity oracle: ``jax.vmap`` of :func:`repro.kernels.tbs_step.ref.apply_ref`
-    (see ref.apply_banked_ref)."""
-    T, cap, D = items.shape
+def gather(items, batch, src, *, block=128, interpret=False):
+    """items [T, cap, L, 128] int32; batch [T, bcap, L, 128] int32;
+    src [T, cap] int32 -> out [T, cap, L, 128] with out[t, i] =
+    items[t, src[t, i]] if src[t, i] < cap else batch[t, src[t, i] - cap]
+    (indices clamped into range as in the oracle,
+    :func:`repro.kernels.tbs_step.ref.apply_banked_ref`)."""
+    T, cap = items.shape[:2]
     bcap = batch.shape[1]
-    capP = src.shape[1]
-    b = min(block, capP)
-    assert capP % b == 0 and capP >= cap, (capP, cap, b)
-    nb = capP // b
-    src3 = src.astype(jnp.int32).reshape(T, capP, 1)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel_banked, cap=cap, bcap=bcap),
+    assert items.dtype == batch.dtype == jnp.int32, (items.dtype, batch.dtype)
+    assert items.shape[2:] == batch.shape[2:] and items.shape[-1] == LANES
+    assert src.shape == (T, cap), (src.shape, T, cap)
+    b = min(block, cap)
+    nb = pl.cdiv(cap, b)
+    src = jnp.pad(src.astype(jnp.int32), ((0, 0), (0, nb * b - cap)))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_kernel, cap=cap, bcap=bcap, block=b),
         grid=(T, nb),
         in_specs=[
-            pl.BlockSpec((1, b, 1), lambda t, bi: (t, bi, 0)),
-            pl.BlockSpec((1, cap, D), lambda t, bi: (t, 0, 0)),
-            pl.BlockSpec((1, bcap, D), lambda t, bi: (t, 0, 0)),
+            pl.BlockSpec((None, 1, b), lambda t, i: (t * nb + i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            hbm, hbm,
         ],
-        out_specs=pl.BlockSpec((1, b, D), lambda t, bi: (t, bi, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, capP, D), items.dtype),
+        out_specs=hbm,
+        out_shape=jax.ShapeDtypeStruct(items.shape, jnp.int32),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        name="tbs_step_gather",
         interpret=interpret,
-    )(src3, items, batch)
-    return out
-
-
-def apply(items, batch, src, *, block=128, interpret=False):
-    """items [cap, D]; batch [bcap, D]; src [capP] int32 (capP >= cap a
-    multiple of ``block``; entries in [0, cap + bcap), rows past cap are
-    wasted work only) -> out [capP, D] with out[i] = items[src[i]] if
-    src[i] < cap else batch[src[i] - cap]."""
-    cap, D = items.shape
-    bcap = batch.shape[0]
-    capP = src.shape[0]
-    b = min(block, capP)
-    assert capP % b == 0 and capP >= cap, (capP, cap, b)
-    nb = capP // b
-    src2 = src.astype(jnp.int32).reshape(capP, 1)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, cap=cap, bcap=bcap),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((b, 1), lambda bi: (bi, 0)),
-            pl.BlockSpec((cap, D), lambda bi: (0, 0)),
-            pl.BlockSpec((bcap, D), lambda bi: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((b, D), lambda bi: (bi, 0)),
-        out_shape=jax.ShapeDtypeStruct((capP, D), items.dtype),
-        interpret=interpret,
-    )(src2, items, batch)
-    return out
+    )(src.reshape(T * nb, 1, b), items, batch)

@@ -3,15 +3,7 @@ touches jax device state (device count is locked on first jax init)."""
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: pass explicit-Auto ``axis_types``
-    where the concept exists (jax >= 0.5), plain mesh otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,16 +11,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     (2 pods = 512 chips). Axes: data = DP/FSDP, model = TP/EP."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many host devices exist (tests/benchmarks)."""
-    return make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_data_mesh(shards: int, axis: str = "data"):
     """1-D mesh over the reservoir co-partitioning axis: what the sharded
     manage loop and the D-R-TBS/D-T-TBS shard_map wrappers run on (the axis
     name must match :data:`repro.core.distributed.AXIS`)."""
-    return make_mesh((shards,), (axis,))
+    return jax.make_mesh((shards,), (axis,), axis_types=(AxisType.Auto,))

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import config as C
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import zoo
 from repro.obs import make_telemetry
 from repro.obs.profile import annotation
@@ -41,6 +42,7 @@ def main(argv=None, telemetry=None):
     ap.add_argument("--telemetry-stdout", action="store_true",
                     help="echo telemetry records to stdout")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     own_telemetry = False
     if telemetry is None and (args.telemetry_dir or args.telemetry_stdout):
         telemetry = make_telemetry(args.telemetry_dir,

@@ -12,9 +12,10 @@ tolerance: `--resume` restarts bit-exactly from the newest checkpoint
 (params, optimizer, reservoir, stream position).
 
 The distributed schemes (paper Sec. 5) run the SAME loop sharded: the driver
-builds a ``data``-axis mesh over ``--shards`` devices (re-exec'ing itself
-with forced host devices when the host has too few -- the per-pod production
-launcher pattern), co-partitions the stream, and runs the whole run as ONE
+builds a ``data``-axis mesh over ``--shards`` devices, which must exist (on a
+CPU-only run, ``JAX_PLATFORMS=cpu``, the driver re-execs itself with that many
+forced host devices before JAX starts; on an accelerator too few devices is
+an error), co-partitions the stream, and runs the whole run as ONE
 fused :func:`repro.manage.make_sharded_run_loop` program: co-partitioned
 reservoir shards, replicated params, one psum per tick. With ``--ckpt-dir``
 the stream is consumed in ``--ckpt-every``-tick segments through
@@ -42,6 +43,15 @@ Examples:
       --preset smoke --ticks 12 --retrain-every 4 --scheme drtbs --shards 8
   PYTHONPATH=src python -m repro.launch.train --arch mamba2_370m \
       --preset smoke --ticks 30 --scheme rtbs --adaptive
+
+Full width on one TPU v5e (16 GB): mamba2_370m is the zoo's model whose
+params, AdamW state and a train step fit one chip (``chip_smoke.py`` runs
+exactly this; the default ``--arch stablelm_12b`` does not fit at
+``--preset full``):
+  PYTHONPATH=src python -m repro.launch.train --arch mamba2_370m \
+      --preset full --scheme rtbs --seq-len 2048 --reservoir 4096 \
+      --batch-per-tick 64 --train-batch 8 --ticks 6 --retrain-every 3 \
+      --retrain-steps 2
 """
 from __future__ import annotations
 
@@ -60,6 +70,7 @@ from repro.bank import make_bank
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from repro.core.api import available_schemes, make_sampler
 from repro.data.streams import KeyedStream, TokenDriftStream, mode_schedule
+from repro.launch.compile_cache import enable_compile_cache
 from repro.manage import (
     init_sharded_state,
     make_bank_run_loop,
@@ -360,6 +371,29 @@ def run_bank(args, adapter, cfg):
     return log
 
 
+def _ensure_devices(shards: int, *, reexec: bool) -> None:
+    """Make sure ``shards`` devices exist for the ``data`` mesh.
+
+    A CPU-only run (``JAX_PLATFORMS=cpu``) launched from the command line
+    re-execs itself with that many forced host devices; the decision is
+    made from the environment, before JAX starts a backend. Anything else
+    with too few devices is an error: the driver never clamps the mesh or
+    moves a run off its accelerator."""
+    flag = "--xla_force_host_platform_device_count"
+    cpu_only = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+    if reexec and cpu_only and flag not in os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "") + f" {flag}={shards}"
+        ).strip()
+        os.execv(sys.executable, [sys.executable] + sys.argv)
+    have = jax.device_count()
+    if have < shards:
+        raise SystemExit(
+            f"--shards {shards} needs {shards} devices; the "
+            f"{jax.default_backend()} backend has {have}"
+        )
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm_12b")
@@ -424,19 +458,10 @@ def main(argv=None):
                     help="per-tick driver: ticks to bracket with the "
                          "profiler (fused loops capture the whole program)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.scheme in DISTRIBUTED_SCHEMES:
-        flag = "--xla_force_host_platform_device_count"
-        if jax.device_count() < args.shards:
-            if argv is None and flag not in os.environ.get("XLA_FLAGS", ""):
-                # same pattern as examples/distributed_reservoir.py and the
-                # per-pod production launcher: the devices must exist before
-                # jax initializes, so re-exec with the flag set
-                os.environ["XLA_FLAGS"] = (
-                    os.environ.get("XLA_FLAGS", "") + f" {flag}={args.shards}"
-                ).strip()
-                os.execv(sys.executable, [sys.executable] + sys.argv)
-            args.shards = jax.device_count()  # programmatic call: clamp
+        _ensure_devices(args.shards, reexec=argv is None)
         # pad the tick batch to a multiple of the mesh BEFORE the sampler is
         # built: dttbs calibrates its acceptance rates p/q on the per-shard
         # arrival rate, and the SGD adapter's LM loss needs padding-free
@@ -478,8 +503,13 @@ def main(argv=None):
     if args.scheme in DISTRIBUTED_SCHEMES:
         return run_sharded(args, adapter, stream, sampler, controller)
 
-    fit = jax.jit(adapter.fit)
+    # the retrain consumes the model state it replaces: donating it keeps
+    # one copy of params + AdamW state on the device, not two
+    fit = jax.jit(adapter.fit, donate_argnums=1)
     eval_fn = jax.jit(adapter.evaluate)
+    step = jax.jit(sampler.step)
+    step_decayed = jax.jit(sampler.step_decayed)
+    extract = jax.jit(sampler.extract)
     proto = jax.ShapeDtypeStruct((args.seq_len,), jnp.int32)
     st = sampler.init(proto)
     model_state = adapter.init()
@@ -532,20 +562,19 @@ def main(argv=None):
         key_t = jax.random.fold_in(jax.random.key(args.seed + 1), t)
         if controller is not None:
             d_t = controller.rate(cstate)
-            st = sampler.step_decayed(key_t, st, batch,
-                                      jnp.int32(args.batch_per_tick), d_t)
+            st = step_decayed(key_t, st, batch,
+                              jnp.int32(args.batch_per_tick), d_t)
             cstate = controller.observe(
                 cstate, jnp.float32(eval_loss),
                 (t + 1) % args.retrain_every == 0)
         else:
-            st = sampler.step(key_t, st, batch,
-                              jnp.int32(args.batch_per_tick))
+            st = step(key_t, st, batch, jnp.int32(args.batch_per_tick))
 
         # ONE realization per tick: the logged |S| is the sample fit trains on
         k_ex, k_fit = jax.random.split(
             jax.random.fold_in(jax.random.key(args.seed + 2), t)
         )
-        view = sampler.extract(k_ex, st)
+        view = extract(k_ex, st)
         size = int(view.size)
 
         # periodic retraining on the realized time-biased sample

@@ -435,11 +435,12 @@ def make_sharded_bank_loop(bank: SamplerBank, model: ModelAdapter, mesh, *,
                                                      trace)
             ) + tail
 
-        jitted = jax.jit(distributed.shard_map(
+        jitted = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(None, axis), P(None, axis)),
             out_specs=tuple(P() for _ in range(3 if telemetry is None
                                               else 4)),
+            check_vma=False,
         ))
         if telemetry is None:
             return jitted

@@ -955,12 +955,13 @@ def make_sharded_run_loop(sampler: Sampler, model: ModelAdapter, mesh, *,
         _check_controllable(sampler)
 
     def build():
-        jitted = jax.jit(distributed.shard_map(
+        jitted = jax.jit(jax.shard_map(
             _sharded_loop_body(sampler, model, retrain_every, superbatch,
                                controller, telemetry),
             mesh=mesh,
             in_specs=_sharded_in_specs(distributed.AXIS),
             out_specs=_replicated_out_specs(3 if telemetry is None else 4),
+            check_vma=False,
         ))
         if telemetry is None:
             return jitted
@@ -1077,10 +1078,11 @@ def make_sharded_manage_step(sampler: Sampler, model: ModelAdapter, mesh, *,
                                               batch_items, bcount[0])
                 return distributed.gather_tree(state), params, metrics
 
-            return jax.jit(distributed.shard_map(
+            return jax.jit(jax.shard_map(
                 step, mesh=mesh,
                 in_specs=(P(), P(), P(), P(), P(axis), P(axis)),
                 out_specs=_replicated_out_specs(),
+                check_vma=False,
             ), donate_argnums=donate)
 
         ctick, _ = _make_controlled_sharded_ticks(sampler, model, controller,
@@ -1094,10 +1096,11 @@ def make_sharded_manage_step(sampler: Sampler, model: ModelAdapter, mesh, *,
             )
             return distributed.gather_tree(state), params, cstate, metrics
 
-        return jax.jit(distributed.shard_map(
+        return jax.jit(jax.shard_map(
             cstep, mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(), P(axis), P(axis)),
             out_specs=(P(), P(), P(), P()),
+            check_vma=False,
         ), donate_argnums=donate)
 
     return _memoized(
@@ -1131,10 +1134,11 @@ def make_sharded_run_farm(sampler: Sampler, model: ModelAdapter, mesh, *,
         def farm_shard(keys, batches, bcounts):
             return jax.vmap(lambda k: loop(k, batches, bcounts))(keys)
 
-        run = jax.jit(distributed.shard_map(
+        run = jax.jit(jax.shard_map(
             farm_shard, mesh=mesh,
             in_specs=_sharded_in_specs(distributed.AXIS),
             out_specs=_replicated_out_specs(),
+            check_vma=False,
         ))
 
         def farm(key, trials: int, batches, bcounts):
@@ -1209,10 +1213,11 @@ def make_sharded_resume_loop(sampler: Sampler, model: ModelAdapter, mesh, *,
             return (distributed.gather_tree(carry[0]),) + carry[1:] + (trace,)
 
         nout = 3 if controller is None else 4
-        jitted = jax.jit(distributed.shard_map(
+        jitted = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(None, axis), P(None, axis), P()),
             out_specs=(P(),) * nout,
+            check_vma=False,
         ))
 
         def run(key, snapshot, params, *rest):

@@ -225,7 +225,19 @@ def make_sgd_adapter(*, init_params: Callable[[], Any],
     if row_loss is None:
         def evaluate(state, batch, bcount):
             del bcount  # scalar loss: caller guarantees no padded rows
-            return loss(state["params"], {batch_field: batch})
+            rows = jax.tree_util.tree_leaves(batch)[0].shape[0]
+            if rows <= train_batch or rows % train_batch:
+                return loss(state["params"], {batch_field: batch})
+            # an eval batch wider than a train batch runs in train-batch
+            # chunks: activations and logits then never exceed what a train
+            # step holds (at 64 x 2048 tokens x a 50k vocab the whole
+            # batch's f32 logits alone are 26 GB). Equal chunks, so the
+            # mean of chunk means is the batch mean.
+            chunks = jax.tree_util.tree_map(
+                lambda a: a.reshape((rows // train_batch, train_batch)
+                                    + a.shape[1:]), batch)
+            return jnp.mean(jax.lax.map(
+                lambda c: loss(state["params"], {batch_field: c}), chunks))
     else:
         def evaluate(state, batch, bcount):
             return _prefix_mean(

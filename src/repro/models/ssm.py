@@ -100,9 +100,14 @@ def ssd_chunked(cfg, x, dt, A, Bm, Cm, init_state=None):
         CB = jnp.broadcast_to(
             CB[:, :, None], (Bsz, G, rep, Q, Q)
         ).reshape(Bsz, H, Q, Q)
-        decay = jnp.exp(clh[..., :, None] - clh[..., None, :])
+        # mask the exponent, not its result: above the diagonal
+        # cl_i - cl_j > 0 grows with the chunk and exp overflows to inf,
+        # and a mask after the exp still sends inf * 0 = NaN into the
+        # gradient (at Q 256 it does)
+        seg = jnp.where(tri[None, None], clh[..., :, None] - clh[..., None, :],
+                        -jnp.inf)
+        decay = jnp.exp(seg)
         scores = CB.astype(jnp.float32) * decay * dt_n.transpose(0, 2, 1)[:, :, None, :]
-        scores = jnp.where(tri[None, None], scores, 0.0)
         y_intra = jnp.einsum("bhqk,bkhp->bqhp", scores.astype(x.dtype), x_n)
         # inter: y_inter[i] = C_i . (state_prev * exp(cl_i))
         Ch = jnp.broadcast_to(

@@ -37,17 +37,10 @@ def annotation(name: str):
 def profile_span(dir: str, *, create_perfetto_link: bool = False):
     """Capture a profiler trace of the enclosed region into ``dir``.
 
-    Exceptions inside the region still stop the trace; a failure to START
-    the profiler (e.g. another trace already active) degrades to a no-op
-    span rather than killing the run -- profiling must never be the reason
-    a training run dies."""
-    try:
-        jax.profiler.start_trace(dir,
-                                 create_perfetto_link=create_perfetto_link)
-    except Exception as e:  # pragma: no cover - depends on runtime state
-        print(f"[obs] profiler trace unavailable ({e}); continuing unprofiled")
-        yield False
-        return
+    Exceptions inside the region still stop the trace. A failure to START
+    the profiler (e.g. another trace already active) propagates: a run
+    that was asked for a trace does not carry on without one."""
+    jax.profiler.start_trace(dir, create_perfetto_link=create_perfetto_link)
     try:
         yield True
     finally:

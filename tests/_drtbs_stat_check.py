@@ -81,9 +81,10 @@ def main():
         return items, nfull[:, None], partial, weight, tweight, oflow[:, None]
 
     smapped = jax.jit(
-        dist.shard_map(
+        jax.shard_map(
             lambda *a: fix_dims_post(shard_fn(*a)),
             mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
     )
 

@@ -176,11 +176,12 @@ def test_extract_mask_sum_equals_size_distributed(scheme):
                          s.size_global(kk, state)[None]))
         return outs
 
-    f = jax.jit(dist.shard_map(
+    f = jax.jit(jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(None, dist.AXIS), P(None, dist.AXIS)),
         out_specs=[(P(dist.AXIS), P(dist.AXIS), P(dist.AXIS),
                     P(), P(), P())] * nkeys,
+        check_vma=False,
     ))
     bitems = jnp.arange(3 * nsh * bcap_s, dtype=jnp.int32).reshape(
         3, nsh * bcap_s) + 1
@@ -232,10 +233,11 @@ def test_distributed_roundtrip_under_shard_map(scheme):
         view = s.extract(jax.random.fold_in(key, 9), state)
         return view.mask, view.size[None]
 
-    f = jax.jit(dist.shard_map(
+    f = jax.jit(jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(None, dist.AXIS), P(None, dist.AXIS)),
         out_specs=(P(dist.AXIS), P(dist.AXIS)),
+        check_vma=False,
     ))
     bitems = jnp.arange(3 * nsh * bcap_s, dtype=jnp.int32).reshape(
         3, nsh * bcap_s) + 1

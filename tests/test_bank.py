@@ -152,6 +152,12 @@ def test_bank_rtbs_step_bit_parity_with_vmap_of_single():
     d = jnp.float32(math.exp(-lam))
     bank = make_bank("rtbs", num_keys=K, n=n, lam=lam, bcap=bcap)
     bstep = jax.jit(bank.step)
+    # both sides are compiled programs: the reference is ONE jitted vmap of
+    # the single-reservoir step over the touched keys, so the weight update
+    # W <- d W + c is rounded as in the fused bank program (an eager
+    # per-key step rounds it differently)
+    ref_step = jax.jit(jax.vmap(
+        lambda k, s, b, c, p: rtbs.step(k, s, b, c, n=n, decay=p * d)))
     st = bank.init(PROTO)
     rs = np.random.RandomState(3)
     key0 = jax.random.key(7)
@@ -167,24 +173,25 @@ def test_bank_rtbs_step_bit_parity_with_vmap_of_single():
         nfull = np.asarray(st.nfull).copy()
         C = np.asarray(st.weight).copy()
         W = np.asarray(st.total_weight).copy()
-        for i in range(int(r.ntouched)):
-            k_id = int(r.touched[i])
-            st_k = rtbs.RTBSState(
-                lat=lt.Latent(items=jnp.asarray(exp_items[k_id]),
-                              nfull=jnp.int32(nfull[k_id]),
-                              weight=jnp.float32(C[k_id])),
-                total_weight=jnp.float32(W[k_id]),
-            )
-            out = rtbs.step(
-                jax.random.fold_in(kt, k_id), st_k,
-                jax.tree_util.tree_map(lambda a: a[i], sub), r.counts[i],
-                n=n, decay=jnp.float32(pend[k_id]),
-            )
-            exp_items[k_id] = np.asarray(out.lat.items)
-            nfull[k_id] = int(out.lat.nfull)
-            C[k_id] = np.float32(out.lat.weight)
-            W[k_id] = np.float32(out.total_weight)
-            pend[k_id] = 1.0
+        nt = int(r.ntouched)
+        ids = np.asarray(r.touched)[:nt]
+        st_t = rtbs.RTBSState(
+            lat=lt.Latent(items=jnp.asarray(exp_items[ids]),
+                          nfull=jnp.asarray(nfull[ids]),
+                          weight=jnp.asarray(C[ids])),
+            total_weight=jnp.asarray(W[ids]),
+        )
+        out = ref_step(
+            jax.vmap(lambda k_id: jax.random.fold_in(kt, k_id))(
+                jnp.asarray(ids)),
+            st_t, jax.tree_util.tree_map(lambda a: a[:nt], sub),
+            r.counts[:nt], st.pending[ids],
+        )
+        exp_items[ids] = np.asarray(out.lat.items)
+        nfull[ids] = np.asarray(out.lat.nfull)
+        C[ids] = np.asarray(out.lat.weight)
+        W[ids] = np.asarray(out.total_weight)
+        pend[ids] = 1.0
 
         st = bstep(kt, st, keys, payload, jnp.int32(b))
         np.testing.assert_array_equal(np.asarray(st.items), exp_items)

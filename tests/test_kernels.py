@@ -118,6 +118,30 @@ def test_ssd_model_path_matches_kernel():
     np.testing.assert_allclose(np.asarray(st1), np.asarray(st2), atol=2e-3, rtol=2e-3)
 
 
+def test_ssd_chunked_gradient_finite_at_full_chunk():
+    """At the configs' chunk of 256 the in-chunk decay exponent above the
+    diagonal overflows exp; the gradient must still be finite (a retrain of
+    a full-width mamba2 otherwise turns every param NaN)."""
+    from repro.config import ModelConfig
+    from repro.models import ssm as S
+
+    cfg = ModelConfig(name="t", family="ssm", num_layers=1, d_model=32,
+                      ssm_state=4, ssm_head_dim=16, ssm_groups=1,
+                      ssm_chunk=256)
+    B, Sq, H, P = 1, 256, cfg.ssm_heads, cfg.ssm_head_dim
+    ks = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(ks[0], (B, Sq, H, P))
+    Bm = jax.random.normal(ks[1], (B, Sq, 1, 4))
+    Cm = jax.random.normal(ks[2], (B, Sq, 1, 4))
+    a = -jnp.ones((H,))
+
+    def total(dt):
+        return jnp.sum(S.ssd_chunked(cfg, x, dt, a, Bm, Cm)[0])
+
+    g = jax.grad(total)(jnp.full((B, Sq, H), 0.69))   # softplus(0)
+    assert bool(jnp.isfinite(g).all())
+
+
 # ---------------------------------------------------------------------------
 # reservoir compaction
 # ---------------------------------------------------------------------------
