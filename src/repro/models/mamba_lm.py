@@ -3,6 +3,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.obs.profile import scope as _scope
+
 from . import layers as L
 from . import ssm as S
 from . import transformer as T
@@ -32,13 +34,16 @@ def forward(cfg, params, batch):
     x, _ = T._embed_inputs(cfg, params, batch)
 
     def layer(h, p):
-        y, _ = S.apply_ssm(cfg, p["ssm"], L.apply_norm(cfg, p["ln"], h))
+        with _scope("lm.norm"):
+            u = L.apply_norm(cfg, p["ln"], h)
+        y, _ = S.apply_ssm(cfg, p["ssm"], u)
         return h + y, None
 
     fn = jax.checkpoint(layer) if cfg.remat else layer
     x, _ = T.scan_or_unroll(cfg, fn, x, params["blocks"])
-    x = L.apply_norm(cfg, params["final_norm"], x)
-    return T.logits_from_hidden(cfg, params, x)
+    with _scope("lm.head"):
+        x = L.apply_norm(cfg, params["final_norm"], x)
+        return T.logits_from_hidden(cfg, params, x)
 
 
 def prefill(cfg, params, batch, max_len):

@@ -16,6 +16,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profile import scope as _scope
+
 from . import layers as L
 
 
@@ -157,22 +159,27 @@ def apply_ssm(cfg, p, u, *, init_state=None):
     H, P = cfg.ssm_heads, cfg.ssm_head_dim
     G, N = cfg.ssm_groups, cfg.ssm_state
     dt_ = u.dtype
-    proj = jnp.einsum("bsd,dk->bsk", u, p["in_proj"].astype(dt_))
+    with _scope("ssm.in_proj"):
+        proj = jnp.einsum("bsd,dk->bsk", u, p["in_proj"].astype(dt_))
     z, xBC_raw, dtv = _split_proj(cfg, proj)
     conv_tail = xBC_raw[:, -(cfg.ssm_conv_width - 1):, :]
-    xBC = _causal_conv(cfg, p, xBC_raw)
+    with _scope("ssm.conv"):
+        xBC = _causal_conv(cfg, p, xBC_raw)
     x, Bm, Cm = _split_xbc(cfg, xBC)
     Bsz, S = x.shape[0], x.shape[1]
     x = x.reshape(Bsz, S, H, P)
     Bm = Bm.reshape(Bsz, S, G, N)
     Cm = Cm.reshape(Bsz, S, G, N)
-    dtv = jax.nn.softplus(dtv.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, final_state = ssd_chunked(cfg, x, dtv, A, Bm, Cm, init_state=init_state)
-    y = y + x * p["D"].astype(dt_)[None, None, :, None]
+    with _scope("ssm.ssd"):
+        dtv = jax.nn.softplus(dtv.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
+        y, final_state = ssd_chunked(cfg, x, dtv, A, Bm, Cm, init_state=init_state)
+        y = y + x * p["D"].astype(dt_)[None, None, :, None]
     y = y.reshape(Bsz, S, cfg.ssm_d_inner)
-    y = L.rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
-    out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(dt_))
+    with _scope("ssm.gate_norm"):
+        y = L.rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
+    with _scope("ssm.out_proj"):
+        out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(dt_))
     return out, SSMCache(conv=conv_tail, state=final_state)
 
 

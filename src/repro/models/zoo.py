@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig, ShapeConfig
+from repro.obs.profile import scope as _scope
 
 from . import encdec, hybrid, mamba_lm, transformer
 
@@ -77,19 +78,20 @@ def loss_fn(cfg, forward, params, batch):
     """Next-token cross entropy in f32 (padded-vocab logits; labels < vocab)."""
     logits = forward(params, batch)
     tokens = batch["tokens"]
-    # frontend prefix (vlm): loss only over the text segment
-    offset = logits.shape[1] - tokens.shape[1]
-    logits = logits[:, offset:]
-    logits = logits[:, :-1].astype(jnp.float32)
-    labels = tokens[:, 1:]
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    mask = batch.get("loss_mask")
-    nll = logz - gold
-    if mask is not None:
-        m = mask[:, 1:].astype(jnp.float32)
-        return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
-    return jnp.mean(nll)
+    with _scope("lm.loss"):
+        # frontend prefix (vlm): loss only over the text segment
+        offset = logits.shape[1] - tokens.shape[1]
+        logits = logits[:, offset:]
+        logits = logits[:, :-1].astype(jnp.float32)
+        labels = tokens[:, 1:]
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        mask = batch.get("loss_mask")
+        nll = logz - gold
+        if mask is not None:
+            m = mask[:, 1:].astype(jnp.float32)
+            return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+        return jnp.mean(nll)
 
 
 # ---------------------------------------------------------------------------

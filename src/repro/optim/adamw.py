@@ -11,6 +11,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profile import scope as _scope
+
 
 @dataclasses.dataclass(frozen=True)
 class AdamWConfig:
@@ -48,28 +50,29 @@ def clip_by_global_norm(grads: Any, max_norm: float):
 
 def adamw_update(cfg: AdamWConfig, grads, opt_state, params, lr_scale=1.0):
     """One AdamW step. Returns (new_params, new_opt_state, metrics)."""
-    grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
-    count = opt_state["count"] + 1
-    b1c = 1.0 - cfg.b1 ** count.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** count.astype(jnp.float32)
-    lr = cfg.lr * jnp.asarray(lr_scale, jnp.float32)
+    with _scope("train.adamw"):
+        grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
+        count = opt_state["count"] + 1
+        b1c = 1.0 - cfg.b1 ** count.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** count.astype(jnp.float32)
+        lr = cfg.lr * jnp.asarray(lr_scale, jnp.float32)
 
-    def upd(p, g, m, v):
-        g32 = g.astype(jnp.float32)
-        m = cfg.b1 * m.astype(jnp.float32) + (1 - cfg.b1) * g32
-        v = cfg.b2 * v.astype(jnp.float32) + (1 - cfg.b2) * g32 * g32
-        mh = m / b1c
-        vh = v / b2c
-        step = mh / (jnp.sqrt(vh) + cfg.eps) + cfg.weight_decay * p.astype(jnp.float32)
-        newp = p.astype(jnp.float32) - lr * step
-        return newp.astype(p.dtype), m.astype(p.dtype), v.astype(p.dtype)
+        def upd(p, g, m, v):
+            g32 = g.astype(jnp.float32)
+            m = cfg.b1 * m.astype(jnp.float32) + (1 - cfg.b1) * g32
+            v = cfg.b2 * v.astype(jnp.float32) + (1 - cfg.b2) * g32 * g32
+            mh = m / b1c
+            vh = v / b2c
+            step = mh / (jnp.sqrt(vh) + cfg.eps) + cfg.weight_decay * p.astype(jnp.float32)
+            newp = p.astype(jnp.float32) - lr * step
+            return newp.astype(p.dtype), m.astype(p.dtype), v.astype(p.dtype)
 
-    flat_p, tdef = jax.tree_util.tree_flatten(params)
-    flat_g = jax.tree_util.tree_leaves(grads)
-    flat_m = jax.tree_util.tree_leaves(opt_state["m"])
-    flat_v = jax.tree_util.tree_leaves(opt_state["v"])
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
-    new_m = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
-    new_v = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])
+        flat_p, tdef = jax.tree_util.tree_flatten(params)
+        flat_g = jax.tree_util.tree_leaves(grads)
+        flat_m = jax.tree_util.tree_leaves(opt_state["m"])
+        flat_v = jax.tree_util.tree_leaves(opt_state["v"])
+        out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
+        new_m = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
+        new_v = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])
     return new_p, {"m": new_m, "v": new_v, "count": count}, {"grad_norm": gnorm}
