@@ -1,34 +1,21 @@
-"""jit wrapper for the SSD kernel (model-layout adapters + CPU interpret)."""
+"""The SSD kernel in the model's layout (``models/ssm.py``)."""
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from . import kernel
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
-
-
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def ssd_scan(x, dt, a, Bm, Cm, *, chunk=256):
-    """Model layout: x [B,S,H,P]; dt [B,S,H] (post-softplus); a [H] (<0);
-    Bm/Cm [B,S,G,N] -> (y [B,S,H,P], state [B,H,N,P])."""
-    B, S, H, P = x.shape
-    G, N = Bm.shape[2], Bm.shape[3]
-    xf = x.transpose(0, 2, 1, 3).reshape(B * H, S, P)
-    dtf = dt.transpose(0, 2, 1).reshape(B * H, S, 1)
-    af = a.reshape(H, 1).astype(jnp.float32)
-    Bf = Bm.transpose(0, 2, 1, 3).reshape(B * G, S, N)
-    Cf = Cm.transpose(0, 2, 1, 3).reshape(B * G, S, N)
-    y, st = kernel.ssd_scan_bhsp(
-        xf, dtf, af, Bf, Cf, chunk=chunk, interpret=_on_cpu(),
-        num_heads=H, num_groups=G,
-    )
-    return (
-        y.reshape(B, H, S, P).transpose(0, 2, 1, 3),
-        st.reshape(B, H, N, P),
-    )
+def ssd_fused(xBC, dt, A, D, *, head_dim, groups, state, chunk,
+              interpret=False):
+    """xBC [B, S, H*P + 2*G*N] (the conv output: x, B, C); dt [B, S, H]
+    (post-softplus); A [H] (< 0); D [H] -> (y [B, S, H*P] with the D skip,
+    final state [B, H, N, P] f32)."""
+    Bsz, _, H = dt.shape
+    f32 = jnp.float32
+    y, st = kernel.ssd_fused(
+        xBC, dt.astype(f32).transpose(0, 2, 1), A.astype(f32).reshape(H, 1),
+        D.astype(f32), head_dim=head_dim, groups=groups,
+        state=state, chunk=chunk, interpret=interpret)
+    st = st.reshape(Bsz, state, H, head_dim).transpose(0, 2, 1, 3)
+    return y, st

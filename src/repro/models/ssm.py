@@ -1,10 +1,12 @@
 """Mamba2 block via SSD (state-space duality, arXiv:2405.21060).
 
 Train/prefill use the chunked SSD algorithm: quadratic attention-like math
-inside chunks of length Q + a linear state recurrence across chunks (one
-lax.scan over S/Q chunks carrying the [B,H,N,P] state). Decode is the O(1)
-recurrent update. The in-chunk compute is also available as a Pallas kernel
-(repro/kernels/ssd_scan) validated against the jnp path here.
+inside chunks of length Q + a linear state recurrence across chunks. Decode
+is the O(1) recurrent update. :func:`ssd` routes the chunked forward: a call
+that is not differentiated and is lowered for a TPU runs the fused Pallas
+kernel (repro/kernels/ssd_scan); a differentiated call, or one lowered for
+any other platform, runs :func:`ssd_chunked` (one lax.scan over S/Q chunks
+carrying the [B,H,N,P] state).
 
 Layout: x [B,S,H,P] (H heads, P=head_dim), B/C [B,S,G,N] (G groups, N=state),
 dt [B,S,H], A = -exp(A_log) [H], skip D [H].
@@ -12,10 +14,14 @@ dt [B,S,H], A = -exp(A_log) [H], skip D [H].
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
+import jax.extend
 import jax.numpy as jnp
+from jax.interpreters import batching, mlir
 
+from repro.kernels.ssd_scan import ops as _ssd_ops
 from repro.obs.profile import scope as _scope
 
 from . import layers as L
@@ -68,7 +74,7 @@ def _causal_conv(cfg, p, xBC):
     return jax.nn.silu(out + p["conv_b"].astype(xBC.dtype))
 
 
-def ssd_chunked(cfg, x, dt, A, Bm, Cm, init_state=None):
+def ssd_chunked(cfg, x, dt, A, Bm, Cm):
     """Chunked SSD. x [B,S,H,P], dt [B,S,H] (post-softplus), A [H] (<0),
     Bm/Cm [B,S,G,N]. Returns (y [B,S,H,P], final_state [B,H,N,P])."""
     Bsz, S, H, P = x.shape
@@ -84,8 +90,7 @@ def ssd_chunked(cfg, x, dt, A, Bm, Cm, init_state=None):
     xc, dtc = chunk_view(x), chunk_view(dt)
     Bc, Cc = chunk_view(Bm), chunk_view(Cm)
 
-    s0 = (jnp.zeros((Bsz, H, N, P), jnp.float32)
-          if init_state is None else init_state.astype(jnp.float32))
+    s0 = jnp.zeros((Bsz, H, N, P), jnp.float32)
     ii = jnp.arange(Q)
     tri = ii[:, None] >= ii[None, :]
 
@@ -137,6 +142,93 @@ def ssd_chunked(cfg, x, dt, A, Bm, Cm, init_state=None):
     return y, final_state
 
 
+def _ssd_jnp(cfg, xBC, dt, A, D):
+    """:func:`ssd_chunked` on the conv output, with the D skip."""
+    H, P = cfg.ssm_heads, cfg.ssm_head_dim
+    G, N = cfg.ssm_groups, cfg.ssm_state
+    x, Bm, Cm = _split_xbc(cfg, xBC)
+    Bsz, S = x.shape[0], x.shape[1]
+    x = x.reshape(Bsz, S, H, P)
+    Bm = Bm.reshape(Bsz, S, G, N)
+    Cm = Cm.reshape(Bsz, S, G, N)
+    y, final_state = ssd_chunked(cfg, x, dt, A, Bm, Cm)
+    y = y + x * D.astype(x.dtype)[None, None, :, None]
+    return y.reshape(Bsz, S, cfg.ssm_d_inner), final_state
+
+
+def _ssd_fused(cfg, xBC, dt, A, D):
+    """The fused Pallas kernel on the conv output, with the D skip."""
+    with _scope("ssm.ssd_fused"):
+        return _ssd_ops.ssd_fused(
+            xBC, dt, A, D, head_dim=cfg.ssm_head_dim, groups=cfg.ssm_groups,
+            state=cfg.ssm_state, chunk=cfg.ssm_chunk)
+
+
+# The SSD forward as one primitive whose lowering follows the platform the
+# program is lowered for: the fused kernel on a TPU, :func:`_ssd_jnp`
+# elsewhere (``lax.platform_dependent`` would choose the same, through a
+# ``cond`` whose branch name then lands in every op_name of the jnp path).
+_ssd_forward_p = jax.extend.core.Primitive("ssd_forward")
+_ssd_forward_p.multiple_results = True
+
+
+@_ssd_forward_p.def_impl
+def _ssd_forward_impl(*args, cfg):
+    return jax.jit(functools.partial(_ssd_forward_p.bind, cfg=cfg))(*args)
+
+
+@_ssd_forward_p.def_abstract_eval
+def _ssd_forward_abstract(xBC, dt, A, D, *, cfg):
+    Bsz, S = xBC.shape[:2]
+    return (jax.core.ShapedArray((Bsz, S, cfg.ssm_d_inner), xBC.dtype),
+            jax.core.ShapedArray((Bsz, cfg.ssm_heads, cfg.ssm_state,
+                                  cfg.ssm_head_dim), jnp.float32))
+
+
+def _ssd_forward_batch(args, dims, *, cfg):
+    """vmap: rows are independent, so the mapped axis joins the batch; a
+    mapped A or D runs the jnp path under vmap."""
+    if dims[2] is not None or dims[3] is not None:
+        return jax.vmap(functools.partial(_ssd_jnp, cfg), dims)(*args), (0, 0)
+    n = next(a.shape[d] for a, d in zip(args, dims) if d is not None)
+    xBC, dt = (jnp.broadcast_to(a, (n,) + a.shape) if d is None
+               else jnp.moveaxis(a, d, 0) for a, d in zip(args[:2], dims[:2]))
+    outs = _ssd_forward_p.bind(xBC.reshape((-1,) + xBC.shape[2:]),
+                               dt.reshape((-1,) + dt.shape[2:]), *args[2:],
+                               cfg=cfg)
+    return [o.reshape((n, -1) + o.shape[1:]) for o in outs], (0, 0)
+
+
+batching.primitive_batchers[_ssd_forward_p] = _ssd_forward_batch
+mlir.register_lowering(_ssd_forward_p, mlir.lower_fun(
+    lambda *args, cfg: _ssd_jnp(cfg, *args), multiple_results=True))
+mlir.register_lowering(_ssd_forward_p, mlir.lower_fun(
+    lambda *args, cfg: _ssd_fused(cfg, *args), multiple_results=True),
+    platform="tpu")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def ssd(cfg, xBC, dt, A, D):
+    """Chunked SSD forward with the D skip: xBC [B,S,conv_dim] (conv output),
+    dt [B,S,H] (post-softplus, f32), A [H] (<0), D [H] -> (y [B,S,d_inner],
+    final_state [B,H,N,P] f32). Lowered for a TPU the forward is the fused
+    kernel; differentiated, it is ``jax.vjp`` of :func:`ssd_chunked`, so
+    the gradient is that path's, bit for bit."""
+    return tuple(_ssd_forward_p.bind(xBC, dt, A, D, cfg=cfg))
+
+
+def _ssd_fwd(cfg, xBC, dt, A, D):
+    return jax.vjp(functools.partial(_ssd_jnp, cfg), xBC, dt, A, D)
+
+
+def _ssd_bwd(cfg, vjp_fn, ct):
+    del cfg
+    return vjp_fn(ct)
+
+
+ssd.defvjp(_ssd_fwd, _ssd_bwd)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class SSMCache:
@@ -153,11 +245,9 @@ def init_ssm_cache(cfg, batch, dtype):
     )
 
 
-def apply_ssm(cfg, p, u, *, init_state=None):
+def apply_ssm(cfg, p, u):
     """Full-sequence Mamba2 block: u [B,S,D] -> ([B,S,D], SSMCache).
     The returned cache (final state + conv tail) makes this the prefill path."""
-    H, P = cfg.ssm_heads, cfg.ssm_head_dim
-    G, N = cfg.ssm_groups, cfg.ssm_state
     dt_ = u.dtype
     with _scope("ssm.in_proj"):
         proj = jnp.einsum("bsd,dk->bsk", u, p["in_proj"].astype(dt_))
@@ -165,17 +255,10 @@ def apply_ssm(cfg, p, u, *, init_state=None):
     conv_tail = xBC_raw[:, -(cfg.ssm_conv_width - 1):, :]
     with _scope("ssm.conv"):
         xBC = _causal_conv(cfg, p, xBC_raw)
-    x, Bm, Cm = _split_xbc(cfg, xBC)
-    Bsz, S = x.shape[0], x.shape[1]
-    x = x.reshape(Bsz, S, H, P)
-    Bm = Bm.reshape(Bsz, S, G, N)
-    Cm = Cm.reshape(Bsz, S, G, N)
     with _scope("ssm.ssd"):
         dtv = jax.nn.softplus(dtv.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
         A = -jnp.exp(p["A_log"].astype(jnp.float32))
-        y, final_state = ssd_chunked(cfg, x, dtv, A, Bm, Cm, init_state=init_state)
-        y = y + x * p["D"].astype(dt_)[None, None, :, None]
-    y = y.reshape(Bsz, S, cfg.ssm_d_inner)
+        y, final_state = ssd(cfg, xBC, dtv, A, p["D"])
     with _scope("ssm.gate_norm"):
         y = L.rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
     with _scope("ssm.out_proj"):

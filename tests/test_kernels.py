@@ -58,23 +58,41 @@ def test_flash_attention_block_shape_invariance():
 # ---------------------------------------------------------------------------
 # ssd scan
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize(
-    "B,S,H,G,N,P,chunk,dtype",
-    [
-        (2, 64, 4, 1, 16, 16, 16, jnp.float32),
-        (1, 128, 4, 2, 32, 16, 32, jnp.float32),   # 2 groups
-        (2, 64, 2, 2, 16, 32, 64, jnp.float32),    # chunk == S
-        (1, 64, 4, 1, 16, 16, 16, jnp.bfloat16),
-    ],
-)
-def test_ssd_scan_matches_recurrence(B, S, H, G, N, P, chunk, dtype):
-    ks = jax.random.split(jax.random.key(2), 5)
+def _ssd_inputs(B, S, H, G, N, P, dtype, key=2):
+    """Model-layout SSD inputs: the conv output xBC [B, S, H*P + 2*G*N] and
+    its x [B,S,H,P], B and C [B,S,G,N]; dt [B,S,H]; A [H] < 0; D [H]."""
+    ks = jax.random.split(jax.random.key(key), 6)
     x = jax.random.normal(ks[0], (B, S, H, P), dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H))) * 0.5
     a = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
-    Bm = jax.random.normal(ks[3], (B, S, G, N), dtype) * 0.5
-    Cm = jax.random.normal(ks[4], (B, S, G, N), dtype) * 0.5
-    y, st = ssd_ops.ssd_scan(x, dt, a, Bm, Cm, chunk=chunk)
+    Bm = (jax.random.normal(ks[3], (B, S, G, N)) * 0.5).astype(dtype)
+    Cm = (jax.random.normal(ks[4], (B, S, G, N)) * 0.5).astype(dtype)
+    D = jax.random.normal(ks[5], (H,))
+    xbc = jnp.concatenate([x.reshape(B, S, H * P), Bm.reshape(B, S, G * N),
+                           Cm.reshape(B, S, G * N)], axis=-1)
+    return xbc, x, dt, a, Bm, Cm, D
+
+
+@pytest.mark.parametrize(
+    "B,S,H,G,N,P,chunk,dtype,heads_per_block",
+    [
+        (2, 64, 4, 1, 16, 16, 16, jnp.float32, None),  # one block of 4 heads
+        (1, 128, 4, 2, 32, 16, 32, jnp.float32, None),   # 2 groups
+        (2, 64, 8, 2, 16, 32, 64, jnp.float32, 2),     # chunk == S, 4 blocks
+        (1, 64, 4, 1, 16, 16, 16, jnp.bfloat16, 2),
+    ],
+)
+def test_ssd_scan_matches_recurrence(B, S, H, G, N, P, chunk, dtype,
+                                     heads_per_block, monkeypatch):
+    """The fused kernel (interpreted) against the per-token recurrence."""
+    from repro.kernels.ssd_scan import kernel as ssd_kernel
+
+    if heads_per_block:   # more than one block of heads at these widths
+        monkeypatch.setattr(ssd_kernel, "head_block",
+                            lambda *shape: heads_per_block)
+    xbc, x, dt, a, Bm, Cm, D = _ssd_inputs(B, S, H, G, N, P, dtype)
+    y, st = ssd_ops.ssd_fused(xbc, dt, a, D, head_dim=P, groups=G, state=N,
+                              chunk=chunk, interpret=True)
     # oracle: exact per-token recurrence with per-head broadcast B/C
     rep = H // G
     Bh = jnp.repeat(Bm, rep, axis=2).transpose(0, 2, 1, 3).reshape(B * H, S, N)
@@ -83,11 +101,13 @@ def test_ssd_scan_matches_recurrence(B, S, H, G, N, P, chunk, dtype):
     dtf = dt.transpose(0, 2, 1).reshape(B * H, S)
     af = jnp.tile(a, B)
     y_ref, st_ref = ssd_ref.ssd_ref(xf, dtf, af, Bh, Ch)
-    y_ref = y_ref.reshape(B, H, S, P).transpose(0, 2, 1, 3)
+    y_ref = (y_ref.reshape(B, H, S, P).transpose(0, 2, 1, 3).astype(jnp.float32)
+             + x.astype(jnp.float32) * D[:, None])
     st_ref = st_ref.reshape(B, H, N, P)
+    assert y.shape == (B, S, H * P) and y.dtype == dtype
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-3
     np.testing.assert_allclose(
-        np.asarray(y, np.float32), np.asarray(y_ref, np.float32),
+        np.asarray(y, np.float32).reshape(B, S, H, P), np.asarray(y_ref),
         atol=tol, rtol=tol,
     )
     np.testing.assert_allclose(
@@ -95,27 +115,96 @@ def test_ssd_scan_matches_recurrence(B, S, H, G, N, P, chunk, dtype):
     )
 
 
+def _tiny_ssm_cfg(**kw):
+    from repro.config import ModelConfig
+
+    return ModelConfig(name="t", family="ssm", num_layers=1, d_model=32,
+                       ssm_state=16, ssm_head_dim=16, ssm_groups=1,
+                       ssm_chunk=16, **kw)
+
+
 def test_ssd_model_path_matches_kernel():
     """The model's jnp chunked path and the Pallas kernel agree."""
-    from repro.config import ModelConfig
     from repro.models import ssm as S
 
-    cfg = ModelConfig(
-        name="t", family="ssm", num_layers=1, d_model=32, ssm_state=16,
-        ssm_head_dim=16, ssm_groups=1, ssm_chunk=16,
-    )
+    cfg = _tiny_ssm_cfg()
     B, Sq = 2, 64
     H, P = cfg.ssm_heads, cfg.ssm_head_dim
-    ks = jax.random.split(jax.random.key(3), 5)
-    x = jax.random.normal(ks[0], (B, Sq, H, P))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, Sq, H))) * 0.5
-    a = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
-    Bm = jax.random.normal(ks[3], (B, Sq, 1, 16)) * 0.5
-    Cm = jax.random.normal(ks[4], (B, Sq, 1, 16)) * 0.5
-    y1, st1 = S.ssd_chunked(cfg, x, dt, a, Bm, Cm)
-    y2, st2 = ssd_ops.ssd_scan(x, dt, a, Bm, Cm, chunk=16)
+    xbc, _, dt, a, _, _, D = _ssd_inputs(B, Sq, H, 1, 16, P, jnp.float32,
+                                         key=3)
+    y1, st1 = S._ssd_jnp(cfg, xbc, dt, a, D)
+    y2, st2 = ssd_ops.ssd_fused(xbc, dt, a, D, head_dim=P, groups=1,
+                                state=16, chunk=16, interpret=True)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(st1), np.asarray(st2), atol=2e-3, rtol=2e-3)
+
+
+def test_ssd_kernel_finite_where_the_chunk_exponent_overflows():
+    """At chunk 256 the in-chunk exponent cl_i - cl_j above the diagonal
+    reaches ~177 here, past exp's f32 range: the kernel masks it before the
+    exp, so the forward stays finite and matches the recurrence."""
+    B, S, H, G, N, P = 1, 256, 2, 1, 8, 16
+    xbc, x, dt, _, Bm, Cm, D = _ssd_inputs(B, S, H, G, N, P, jnp.float32,
+                                           key=5)
+    dt = jnp.full((B, S, H), 0.69)          # softplus(0)
+    a = -jnp.ones((H,))
+    y, st = ssd_ops.ssd_fused(xbc, dt, a, D, head_dim=P, groups=G, state=N,
+                              chunk=256, interpret=True)
+    assert bool(jnp.isfinite(y).all()) and bool(jnp.isfinite(st).all())
+    y_ref, _ = ssd_ref.ssd_ref(
+        x.transpose(0, 2, 1, 3).reshape(B * H, S, P),
+        dt.transpose(0, 2, 1).reshape(B * H, S), jnp.tile(a, B),
+        jnp.repeat(Bm, H, axis=2).transpose(0, 2, 1, 3).reshape(B * H, S, N),
+        jnp.repeat(Cm, H, axis=2).transpose(0, 2, 1, 3).reshape(B * H, S, N))
+    y_ref = y_ref.reshape(B, H, S, P).transpose(0, 2, 1, 3) + x * D[:, None]
+    np.testing.assert_allclose(np.asarray(y).reshape(B, S, H, P),
+                               np.asarray(y_ref), atol=1e-3, rtol=1e-3)
+
+
+def test_ssd_custom_vjp_routes_primal_to_kernel_and_gradient_to_jnp(
+        monkeypatch):
+    """``ssm.ssd``: its gradient is bit for bit the plain ``ssd_chunked``
+    path's, and its primal is the kernel's output where the program is
+    lowered for the kernel (here: the kernel, interpreted, put in place of
+    the CPU lowering by the test)."""
+    from jax._src.interpreters import mlir
+    from repro.models import ssm as S
+
+    cfg = _tiny_ssm_cfg()
+    p = S.ssm_params(cfg, jax.random.key(7))
+    u = jax.random.normal(jax.random.key(8), (2, 64, cfg.d_model))
+
+    def loss(p, u):
+        return jnp.sum(jnp.tanh(S.apply_ssm(cfg, p, u)[0]))
+
+    routed = jax.jit(jax.grad(loss, argnums=(0, 1)))(p, u)
+    # today's path: apply_ssm with the jnp SSD and no custom_vjp
+    with monkeypatch.context() as m:
+        m.setattr(S, "ssd", S._ssd_jnp)
+        plain = jax.jit(jax.grad(loss, argnums=(0, 1)))(p, u)
+    for g1, g2 in zip(jax.tree_util.tree_leaves(routed),
+                      jax.tree_util.tree_leaves(plain)):
+        np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
+
+    xbc, _, dt, a, _, _, D = _ssd_inputs(2, 64, cfg.ssm_heads, 1, 16, 16,
+                                         jnp.float32, key=9)
+
+    def kernel(*args):
+        return ssd_ops.ssd_fused(*args, head_dim=16, groups=1, state=16,
+                                 chunk=16, interpret=True)
+
+    cpu = mlir._platform_specific_lowerings["cpu"]
+    with monkeypatch.context() as m:
+        m.setitem(cpu, S._ssd_forward_p, mlir.LoweringRuleEntry(
+            mlir.lower_fun(lambda *args, cfg: kernel(*args)), inline=True))
+        y, st = jax.jit(lambda *args: S.ssd(cfg, *args))(xbc, dt, a, D)
+    y_k, st_k = jax.jit(kernel)(xbc, dt, a, D)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_k))
+    np.testing.assert_array_equal(np.asarray(st), np.asarray(st_k))
+    # lowered for the CPU, the primal is the jnp path's
+    y_j, _ = jax.jit(lambda *args: S.ssd(cfg, *args))(xbc, dt, a, D)
+    y_p, _ = jax.jit(lambda *args: S._ssd_jnp(cfg, *args))(xbc, dt, a, D)
+    np.testing.assert_array_equal(np.asarray(y_j), np.asarray(y_p))
 
 
 def test_ssd_chunked_gradient_finite_at_full_chunk():
@@ -140,6 +229,30 @@ def test_ssd_chunked_gradient_finite_at_full_chunk():
 
     g = jax.grad(total)(jnp.full((B, Sq, H), 0.69))   # softplus(0)
     assert bool(jnp.isfinite(g).all())
+
+
+def test_ssd_forward_under_vmap_equals_a_loop():
+    """vmap of ``ssm.ssd``: a mapped axis of xBC and dt joins the batch, a
+    mapped A runs the jnp path under vmap; both equal a loop of calls."""
+    from repro.models import ssm as S
+
+    cfg = _tiny_ssm_cfg()
+    H = cfg.ssm_heads
+    xbc, _, dt, a, _, _, D = _ssd_inputs(3 * 2, 32, H, 1, 16, 16,
+                                         jnp.float32, key=11)
+    xbc, dt = xbc.reshape((3, 2) + xbc.shape[1:]), dt.reshape((3, 2, 32, H))
+    a3 = a * jnp.arange(1.0, 4.0)[:, None]
+    f = jax.jit(lambda *args: S.ssd(cfg, *args))
+    for got, args in (
+            (jax.vmap(f, (0, 0, None, None))(xbc, dt, a, D),
+             lambda i: (xbc[i], dt[i], a, D)),
+            (jax.vmap(f, (0, None, 0, None))(xbc, dt[0], a3, D),
+             lambda i: (xbc[i], dt[0], a3[i], D))):
+        for i in range(3):
+            want = f(*args(i))
+            for g, w in zip(got, want):
+                np.testing.assert_allclose(np.asarray(g[i]), np.asarray(w),
+                                           atol=1e-5, rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------

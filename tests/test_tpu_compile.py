@@ -1,6 +1,7 @@
 """Compile the main path's Pallas kernels for a TPU v5e that is described,
 not attached, at the sizes the system runs (on-chip payloads: 2048-token
-int32 rows, f32 feature rows, the bank's narrow leaves).
+int32 rows, f32 feature rows, the bank's narrow leaves; the fused SSD
+forward at the SSM configurations' widths).
 
 Nothing runs: a compile that passes says the chip's compiler accepts the
 kernel (tiling, VMEM, lowering), not that its results are right -- the
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.reservoir_compact import ops as rc_ops
+from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.tbs_step import ops as ts_ops
 
 
@@ -78,3 +80,51 @@ def test_reservoir_compact_compiles_for_v5e(one_chip, cap, D, dtype):
         lambda it, m: rc_ops.reservoir_compact(it, m, impl="pallas"),
         one_chip, ((cap, D), dtype), ((cap,), jnp.bool_),
     )
+
+
+@pytest.mark.parametrize("H,P,G,N", [
+    (32, 64, 1, 128),    # mamba2-370m
+    (80, 64, 1, 64),     # zamba2-2.7b
+])
+def test_ssd_fused_compiles_for_v5e(one_chip, H, P, G, N):
+    B, S = 8, 2048       # an eval chunk of the LM cell: 8 rows of 2048 tokens
+    _compile(
+        lambda xbc, dt, a, d: ssd_ops.ssd_fused(
+            xbc, dt, a, d, head_dim=P, groups=G, state=N, chunk=256),
+        one_chip, ((B, S, H * P + 2 * G * N), jnp.bfloat16),
+        ((B, S, H), jnp.float32), ((H,), jnp.float32), ((H,), jnp.float32),
+    )
+
+
+def _lm_lowered(one_chip, fn):
+    """``fn(api, params, batch)`` lowered for the described chip at a small
+    mamba2 (2 layers, d_model 256, the published state and head widths)."""
+    import dataclasses
+
+    from repro.configs.mamba2_370m import CONFIG
+    from repro.models import zoo
+
+    cfg = dataclasses.replace(CONFIG, num_layers=2, d_model=256,
+                              vocab_size=512)
+    api = zoo.build(cfg)
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        spec, jax.eval_shape(api.init_params, jax.random.key(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 512), jnp.int32,
+                                            sharding=one_chip)}
+    return jax.jit(lambda p, b: fn(api, p, b)).lower(params, batch)
+
+
+def test_mamba_lm_forward_lowers_to_the_ssd_kernel(one_chip):
+    lowered = _lm_lowered(one_chip, lambda api, p, b: api.forward(p, b))
+    assert "tpu_custom_call" in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_mamba_lm_gradient_lowers_without_the_ssd_kernel(one_chip):
+    lowered = _lm_lowered(one_chip,
+                          lambda api, p, b: jax.grad(api.loss)(p, b))
+    assert "tpu_custom_call" not in lowered.as_text()
