@@ -52,6 +52,16 @@ class ModelConfig:
     ssm_chunk: int = 256
     # hybrid (zamba2-style): one shared attention block every `attn_every` layers
     attn_every: int = 0
+    # hybrid by layer pattern (granite-4.0-h-style): each layer's mixer,
+    # "mamba" or "attention", each followed by the MLP (models/pattern_lm.py)
+    layer_pattern: Tuple[str, ...] = ()
+    # muP multipliers: h = embed * embedding_multiplier; each residual branch
+    # adds residual_multiplier * branch; logits / logits_scaling; attention
+    # scores * attention_scale (0 -> 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_scale: float = 0.0
     # encoder-decoder (whisper-style)
     is_encoder_decoder: bool = False
     encoder_layers: int = 0
@@ -84,6 +94,10 @@ class ModelConfig:
         return self.d_model // max(self.num_heads, 1)
 
     @property
+    def resolved_attention_scale(self) -> float:
+        return self.attention_scale or self.resolved_head_dim ** -0.5
+
+    @property
     def padded_vocab(self) -> int:
         """Vocab padded to a multiple of 256 for clean 2-axis sharding
         (standard practice; padding rows are never routed to)."""
@@ -112,7 +126,9 @@ class ModelConfig:
         d, hd = self.d_model, self.resolved_head_dim
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         if self.family == "ssm":
-            per = self._ssm_layer_params()
+            # leaves out each layer's conv bias and one of its three
+            # per-head vectors (the count tests/bench pins for mamba2-370m)
+            per = self._ssm_layer_params() - self._ssm_conv_dim - self.ssm_heads
             return emb + self.num_layers * per + d  # final norm
         attn = d * hd * (self.num_heads + 2 * self.num_kv_heads) + self.num_heads * hd * d
         if self.act == "swiglu":
@@ -123,10 +139,14 @@ class ModelConfig:
             mlp = self.num_experts * mlp + d * self.num_experts  # + router
         norms = 2 * d
         per_layer = attn + mlp + norms
+        if self.layer_pattern:
+            n_attn = self.layer_pattern.count("attention")
+            n_ssm = self.num_layers - n_attn
+            return (emb + n_ssm * (self._ssm_layer_params() + mlp + d)
+                    + n_attn * per_layer + d)
         if self.family == "hybrid":
-            n_attn = self.num_layers // max(self.attn_every, 1)
-            per_ssm = self._ssm_layer_params()
-            return emb + self.num_layers * per_ssm + 1 * (attn + 2 * d * self.d_ff) + d
+            # the Mamba2 layers, then the one shared attention + MLP block
+            return emb + self.num_layers * self._ssm_layer_params() + per_layer + d
         total = emb + self.num_layers * per_layer + d
         if self.is_encoder_decoder:
             # encoder layers: self-attn + mlp; decoder adds cross-attn (already in
@@ -136,13 +156,19 @@ class ModelConfig:
             total += enc + cross
         return total
 
+    @property
+    def _ssm_conv_dim(self) -> int:
+        return self.ssm_d_inner + 2 * self.ssm_groups * self.ssm_state
+
     def _ssm_layer_params(self) -> int:
+        """A Mamba2 layer with its pre-norm: in/out projections, conv weight
+        and bias, dt_bias / A_log / D, the gate norm."""
         d, din, ns = self.d_model, self.ssm_d_inner, self.ssm_state
         g, h = self.ssm_groups, self.ssm_heads
         in_proj = d * (2 * din + 2 * g * ns + h)
-        conv = (din + 2 * g * ns) * self.ssm_conv_width
+        conv = self._ssm_conv_dim * (self.ssm_conv_width + 1)
         out = din * d
-        return in_proj + conv + out + 2 * h + din + d
+        return in_proj + conv + out + 3 * h + din + d
 
     def active_param_count(self) -> int:
         """Active params per token (MoE uses top-k of experts) for 6*N_active*D."""
@@ -181,6 +207,7 @@ ARCH_IDS = [
     "stablelm_12b",
     "mistral_large_123b",
     "whisper_large_v3",
+    "granite_4_0_h_micro",
 ]
 
 # external-name -> module-name aliases (assignment ids use dashes/dots)
@@ -195,6 +222,7 @@ ALIASES = {
     "stablelm-12b": "stablelm_12b",
     "mistral-large-123b": "mistral_large_123b",
     "whisper-large-v3": "whisper_large_v3",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 
@@ -222,5 +250,7 @@ def cells(include_skipped: bool = False):
             skip = None
             if shape.name == "long_500k" and not cfg.subquadratic:
                 skip = "full attention: 500k KV decode is infeasible (DESIGN.md §5)"
+            if cfg.layer_pattern:
+                skip = "layer-pattern stack: the dry-run's per-unit cost model has no unit for it"
             if skip is None or include_skipped:
                 yield arch, shape.name, skip

@@ -70,9 +70,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         )
 
 
-def flash_attention_bhsd(q, k, v, *, causal=True, window=0,
+def flash_attention_bhsd(q, k, v, *, causal=True, window=0, scale=None,
                          block_q=128, block_k=128, interpret=False):
-    """q: [BH, S, hd]; k, v: [BKV, T, hd] with BH = BKV * group. -> [BH, S, hd]."""
+    """q: [BH, S, hd]; k, v: [BKV, T, hd] with BH = BKV * group. -> [BH, S, hd].
+    Scores are scaled by ``scale`` (None: 1/sqrt(hd))."""
     BH, S, hd = q.shape
     BKV, T, _ = k.shape
     group = BH // BKV
@@ -80,7 +81,8 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0,
     bk = min(block_k, T)
     assert S % bq == 0 and T % bk == 0
     nq, nk = S // bq, T // bk
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
 
     grid = (BH, nq, nk)
     return pl.pallas_call(

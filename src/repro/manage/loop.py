@@ -591,6 +591,10 @@ def _make_local_tick(sampler: Sampler, model: ModelAdapter,
         k_step, k_extract, k_fit = tick_keys(key, t)
         with _scope("manage.eval"):
             metric = model.evaluate(params, batch_items, bcount)
+        # the retrain takes the model state only once the eval has read it:
+        # unordered, XLA copies the parameters into and out of the retrain
+        # `cond` (a copy of the parameters each, every tick)
+        metric, params = jax.lax.optimization_barrier((metric, params))
         with _scope("manage.sampler_step"):
             state = sampler.step(k_step, state, batch_items, bcount)
 

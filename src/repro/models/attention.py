@@ -61,7 +61,7 @@ def sdpa(cfg, q, k, v, *, q_positions=None, k_positions=None, causal=True,
     G = H // KV
     qg = q.reshape(B, S, KV, G, hd)
     scores = jnp.einsum("bskgh,btkh->bkgst", qg, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(hd))
+    scores = scores * jnp.float32(cfg.resolved_attention_scale)
     if q_positions is None:
         q_positions = jnp.arange(S)[None]
     if k_positions is None:
@@ -84,17 +84,19 @@ def sdpa(cfg, q, k, v, *, q_positions=None, k_positions=None, causal=True,
 def chunked_sdpa(cfg, q, k, v, *, causal=True, window=0, block_q=1024,
                  block_k=1024):
     """Online-softmax (flash-style) attention in pure lax: scan over query
-    blocks, remat'd inner scan over key blocks. Peak memory O(block_q*block_k)
-    instead of O(S*T) -- required for the 32k cells. Same math as :func:`sdpa`
-    (tested); block-masked waste on causal lower blocks is accounted for in the
-    roofline (EXPERIMENTS.md §Roofline note)."""
+    blocks, remat'd inner scan over key blocks, each key step remat'd too (on
+    a v5e the retrain's gradient through the granite cell's 512-blocks turns
+    NaN without it, while the CPU's matches the plain reference). Peak memory
+    O(block_q*block_k) instead of O(S*T) -- required for the 32k cells. Same
+    math as :func:`sdpa` (tested); block-masked waste on causal lower blocks
+    is accounted for in the roofline (EXPERIMENTS.md §Roofline note)."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
     bq, bk = min(block_q, S), min(block_k, T)
     nq, nk = S // bq, T // bk
     assert S % bq == 0 and T % bk == 0, (S, T, bq, bk)
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    scale = jnp.float32(cfg.resolved_attention_scale)
     NEG = jnp.float32(-1e30)
 
     qb = q.reshape(B, nq, bq, KV, G, hd).transpose(1, 0, 2, 3, 4, 5)
@@ -129,7 +131,7 @@ def chunked_sdpa(cfg, q, k, v, *, causal=True, window=0, block_q=1024,
         m0 = jnp.full((B, KV, G, bq), NEG)
         l0 = jnp.zeros((B, KV, G, bq), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(
-            kv_step, (acc0, m0, l0), jnp.arange(nk)
+            jax.checkpoint(kv_step), (acc0, m0, l0), jnp.arange(nk)
         )
         out = acc / jnp.maximum(l[..., None], 1e-30)
         return out.astype(q.dtype)  # [B,KV,G,bq,hd]
@@ -145,7 +147,8 @@ def _attend(cfg, q, k, v, **kw):
 
         if kw.get("k_valid") is None and q.shape[1] == k.shape[1]:
             return fa.flash_attention(
-                q, k, v, causal=kw.get("causal", True), window=kw.get("window", 0)
+                q, k, v, causal=kw.get("causal", True), window=kw.get("window", 0),
+                scale=cfg.resolved_attention_scale,
             )
     S, T = q.shape[1], k.shape[1]
     if cfg.attn_chunk and S >= cfg.attn_chunk and T >= cfg.attn_chunk \

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig, ShapeConfig
 from repro.obs.profile import scope as _scope
 
-from . import encdec, hybrid, mamba_lm, transformer
+from . import encdec, hybrid, mamba_lm, pattern_lm, transformer
 
 VLM_PATCHES = 256  # stubbed vision prefix length (qwen2-vl dynamic-res stub)
 
@@ -35,7 +35,7 @@ def build(cfg: ModelConfig) -> ModelAPI:
     elif fam == "ssm":
         mod = mamba_lm
     elif fam == "hybrid":
-        mod = hybrid
+        mod = pattern_lm if cfg.layer_pattern else hybrid
     elif fam == "audio":
         mod = encdec
     else:

@@ -85,6 +85,7 @@ def test_reservoir_compact_compiles_for_v5e(one_chip, cap, D, dtype):
 @pytest.mark.parametrize("H,P,G,N", [
     (32, 64, 1, 128),    # mamba2-370m
     (80, 64, 1, 64),     # zamba2-2.7b
+    (64, 64, 1, 128),    # granite-4.0-h-micro
 ])
 def test_ssd_fused_compiles_for_v5e(one_chip, H, P, G, N):
     B, S = 8, 2048       # an eval chunk of the LM cell: 8 rows of 2048 tokens
