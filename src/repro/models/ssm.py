@@ -6,7 +6,9 @@ is the O(1) recurrent update. :func:`ssd` routes the chunked forward: a call
 that is not differentiated and is lowered for a TPU runs the fused Pallas
 kernel (repro/kernels/ssd_scan); a differentiated call, or one lowered for
 any other platform, runs :func:`ssd_chunked` (one lax.scan over S/Q chunks
-carrying the [B,H,N,P] state).
+carrying the [B,H,N,P] state). :func:`_in_proj` routes the in-projection by
+differentiation alone: three dots where not differentiated, one dot and its
+split where differentiated.
 
 Layout: x [B,S,H,P] (H heads, P=head_dim), B/C [B,S,G,N] (G groups, N=state),
 dt [B,S,H], A = -exp(A_log) [H], skip D [H].
@@ -221,12 +223,37 @@ def _ssd_fwd(cfg, xBC, dt, A, D):
     return jax.vjp(functools.partial(_ssd_jnp, cfg), xBC, dt, A, D)
 
 
-def _ssd_bwd(cfg, vjp_fn, ct):
+def _vjp_bwd(cfg, vjp_fn, ct):
     del cfg
     return vjp_fn(ct)
 
 
-ssd.defvjp(_ssd_fwd, _ssd_bwd)
+ssd.defvjp(_ssd_fwd, _vjp_bwd)
+
+
+def _in_proj_fused(cfg, u, w):
+    """The in-projection as one dot, split into (z, xBC, dt)."""
+    return _split_proj(cfg, jnp.einsum("bsd,dk->bsk", u, w))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _in_proj(cfg, u, w):
+    """u [B,S,D] @ w [D, 2*d_inner + 2*G*N + H] -> (z, xBC, dt). Not
+    differentiated: three dots on w's column blocks, so each output is laid
+    out as its consumer reads it (one dot's output is laid out S-minor for
+    dt's consumer, and its z and xBC slices are then copied row-major).
+    Differentiated: ``jax.vjp`` of :func:`_in_proj_fused`, so the gradient
+    is that path's, bit for bit."""
+    with _scope("ssm.in_proj_split"):
+        return tuple(jnp.einsum("bsd,dk->bsk", u, wk)
+                     for wk in _split_proj(cfg, w))
+
+
+def _in_proj_fwd(cfg, u, w):
+    return jax.vjp(functools.partial(_in_proj_fused, cfg), u, w)
+
+
+_in_proj.defvjp(_in_proj_fwd, _vjp_bwd)
 
 
 @jax.tree_util.register_dataclass
@@ -250,8 +277,7 @@ def apply_ssm(cfg, p, u):
     The returned cache (final state + conv tail) makes this the prefill path."""
     dt_ = u.dtype
     with _scope("ssm.in_proj"):
-        proj = jnp.einsum("bsd,dk->bsk", u, p["in_proj"].astype(dt_))
-    z, xBC_raw, dtv = _split_proj(cfg, proj)
+        z, xBC_raw, dtv = _in_proj(cfg, u, p["in_proj"].astype(dt_))
     conv_tail = xBC_raw[:, -(cfg.ssm_conv_width - 1):, :]
     with _scope("ssm.conv"):
         xBC = _causal_conv(cfg, p, xBC_raw)
@@ -272,8 +298,7 @@ def decode_ssm(cfg, p, u, cache: SSMCache):
     G, N = cfg.ssm_groups, cfg.ssm_state
     dt_ = u.dtype
     Bsz = u.shape[0]
-    proj = jnp.einsum("bsd,dk->bsk", u, p["in_proj"].astype(dt_))
-    z, xBC, dtv = _split_proj(cfg, proj)
+    z, xBC, dtv = _in_proj_fused(cfg, u, p["in_proj"].astype(dt_))
     # conv over [cache | new token]
     window = jnp.concatenate([cache.conv, xBC], axis=1)       # [B, W, conv]
     conv_out = jnp.einsum(

@@ -207,6 +207,64 @@ def test_ssd_custom_vjp_routes_primal_to_kernel_and_gradient_to_jnp(
     np.testing.assert_array_equal(np.asarray(y_j), np.asarray(y_p))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_in_proj_custom_vjp_routes_gradient_to_the_fused_dot(monkeypatch,
+                                                             dtype):
+    """``ssm._in_proj``: differentiated, the in-projection is bit for bit
+    the one fused dot and its split (f32 weights cast to the activations'
+    dtype, as the model runs them)."""
+    from repro.models import ssm as S
+
+    cfg = _tiny_ssm_cfg()
+    p = S.ssm_params(cfg, jax.random.key(7))
+    u = jax.random.normal(jax.random.key(8), (2, 64, cfg.d_model), dtype)
+
+    def loss(p, u):
+        y = S.apply_ssm(cfg, p, u)[0]
+        return jnp.sum(jnp.tanh(y.astype(jnp.float32)))
+
+    routed = jax.jit(jax.grad(loss, argnums=(0, 1)))(p, u)
+    with monkeypatch.context() as m:
+        m.setattr(S, "_in_proj", S._in_proj_fused)
+        fused = jax.jit(jax.grad(loss, argnums=(0, 1)))(p, u)
+    for g1, g2 in zip(jax.tree_util.tree_leaves(routed),
+                      jax.tree_util.tree_leaves(fused)):
+        np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
+
+
+@pytest.mark.parametrize("dtype,ulps", [(jnp.float32, 0), (jnp.bfloat16, 1)])
+def test_in_proj_primal_splits_the_fused_dot(dtype, ulps):
+    """The primal's three dots give the fused dot's slices: exactly in
+    float32, on integer-valued data whose sums are exact in any order (on
+    random data the CPU's dot sums in an order that follows the output
+    width, a last-bit difference); bfloat16 rounds each f32 sum once, so
+    random data agree within one ulp."""
+    from repro.models import ssm as S
+
+    cfg = _tiny_ssm_cfg()
+    din, gn = cfg.ssm_d_inner, 2 * cfg.ssm_groups * cfg.ssm_state
+    k = din + din + gn + cfg.ssm_heads
+    ku, kw = jax.random.split(jax.random.key(8))
+    if dtype == jnp.float32:
+        u = jax.random.randint(ku, (2, 64, cfg.d_model), -8, 9).astype(dtype)
+        w = jax.random.randint(kw, (cfg.d_model, k), -8, 9).astype(dtype)
+    else:
+        u = jax.random.normal(ku, (2, 64, cfg.d_model), dtype)
+        w = jax.random.normal(kw, (cfg.d_model, k), dtype)
+    split = jax.jit(lambda u, w: S._in_proj(cfg, u, w))(u, w)
+    want = jax.jit(lambda u, w: S._in_proj_fused(cfg, u, w))(u, w)
+    assert [a.shape[-1] for a in split] == [din, din + gn, cfg.ssm_heads]
+    for a, b in zip(split, want):
+        assert a.shape == b.shape and a.dtype == b.dtype == dtype
+        a = np.asarray(a.astype(jnp.float32))
+        b = np.asarray(b.astype(jnp.float32))
+        # one ulp of the dtype at b: float32's spacing, 2**16 times wider
+        # for bfloat16's 7 fraction bits
+        ulp = np.spacing(np.abs(b)) * (2.0 ** 16 if dtype == jnp.bfloat16
+                                       else 1.0)
+        assert np.all(np.abs(a - b) <= ulps * ulp)
+
+
 def test_ssd_chunked_gradient_finite_at_full_chunk():
     """At the configs' chunk of 256 the in-chunk decay exponent above the
     diagonal overflows exp; the gradient must still be finite (a retrain of

@@ -10,6 +10,7 @@ is described inside a fixture, so only the worker that runs these tests
 loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -129,3 +130,43 @@ def test_mamba_lm_gradient_lowers_without_the_ssd_kernel(one_chip):
     lowered = _lm_lowered(one_chip,
                           lambda api, p, b: jax.grad(api.loss)(p, b))
     assert "tpu_custom_call" not in lowered.as_text()
+
+
+def _copied_shapes(hlo: str) -> set[tuple[int, ...]]:
+    """The shapes of the optimized HLO's ``copy`` instructions."""
+    return {tuple(int(n) for n in m.group(1).split(","))
+            for m in re.finditer(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", hlo)}
+
+
+@pytest.mark.parametrize("arch", ["mamba2_370m", "granite_4_0_h_micro"])
+def test_mamba2_eval_projection_has_no_layout_copies(one_chip, arch):
+    """Not differentiated, the in-projection's z and xBC come out of their
+    own dots row-major, as the gate norm, the conv and the SSD kernel read
+    them: no layout copy of either between the projection and the kernel
+    (at 32 heads of d 1024 and at 64 of d 2048)."""
+    import dataclasses
+    import importlib
+
+    from repro.models import ssm as S
+
+    cfg = dataclasses.replace(
+        importlib.import_module(f"repro.configs.{arch}").CONFIG, num_layers=2)
+    B, T = 16, 2048      # an eval tick of the LM cells
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = [jax.tree_util.tree_map(spec, jax.eval_shape(
+        lambda k: S.ssm_params(cfg, k), jax.random.key(i))) for i in range(2)]
+
+    def forward(params, h):
+        for p in params:
+            h = h + S.apply_ssm(cfg, p, h)[0]
+        return h
+
+    hlo = jax.jit(forward).lower(
+        params, spec(jax.ShapeDtypeStruct((B, T, cfg.d_model), jnp.bfloat16))
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo   # the SSD kernel is still there
+    conv_dim = cfg.ssm_d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
+    assert not {(B, T, conv_dim), (B, T, cfg.ssm_d_inner)} & _copied_shapes(hlo)
